@@ -23,8 +23,8 @@ Rows (CSV contract ``name,us_per_call,derived`` + JSON extras):
   stats row); CI's faults-smoke lane validates both schemas.
 * ``faults/lost_acked_writes`` — MUST be 0 at K=2: every write the store
   acknowledged before/during/after the crash is readable after recovery.
-  A non-zero count raises (→ an ERROR row, non-zero exit under
-  ``--strict``) rather than reporting a broken store as data.
+  A non-zero count raises (→ an ERROR row, non-zero harness exit)
+  rather than reporting a broken store as data.
 * ``faults/recovery``          — failover/resync/retry/lease counters
   from the merged meters: proof the run actually crossed a failover and
   shipped a state image, not just idled through the window.

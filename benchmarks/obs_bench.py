@@ -13,7 +13,7 @@ exports machine-checkable — so each row is one of those claims:
 * ``obs/dormant_identity``   — the dormant-plane contract: a hub-carrying
   store's meters, recorded transport trace, and final MN state image are
   byte-identical to a plain store's after the same driven mix.  Raises on
-  any drift (→ an ERROR row, non-zero exit under ``--strict``).
+  any drift (→ an ERROR row, non-zero harness exit).
 * ``obs/spans``              — the span plane saw the run: flush spans
   with queue-wait/coalescing annotations, per-op-kind counters, snapshot
   cadence on the op clock.

@@ -13,8 +13,10 @@ contract (``BENCH_*.json``) future PRs diff against.  Every suite builds
 its stores exclusively through ``repro.api.open_store``, so the JSON also
 records the registry the run saw.
 
+Exits non-zero when any suite produced an ``<suite>/ERROR`` row.
+
 Run:  PYTHONPATH=src python -m benchmarks.run [--quick] [--only lat,scale]
-      [--strict] [--json out.json]
+      [--json out.json]
 """
 
 from __future__ import annotations
@@ -115,8 +117,6 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smaller key sets (CI-speed)")
     ap.add_argument("--only", default=None, help=_only_help())
-    ap.add_argument("--strict", action="store_true",
-                    help="exit non-zero if any suite produced an ERROR row")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows (with extras such as latency "
                          "percentiles) as machine-readable JSON")
@@ -126,6 +126,8 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks.common import emit
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n = 100_000 if args.quick else 300_000
     suites = [(name, lambda fn=fn: fn(args, n)) for name, _b, fn in SUITES]
@@ -162,8 +164,7 @@ def main() -> None:
     if errors:
         print(f"# {len(errors)} ERROR row(s): {', '.join(errors)}",
               file=sys.stderr)
-        if args.strict:
-            sys.exit(1)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

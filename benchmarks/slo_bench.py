@@ -399,7 +399,7 @@ def _acked_writes_row(keys, vals, knee: dict, quick: bool):
 def _dormant_identity_row(keys, vals, quick: bool):
     """A default-config FrontDoor versus calling the stack directly:
     meters, recorded trace, and final MN state must be byte-identical.
-    Raises on any drift (an ERROR row under ``--strict``)."""
+    Raises on any drift (an ERROR row, non-zero harness exit)."""
     n_ops = 2_000 if quick else 6_000
     idx = C.zipf_indices(len(keys), n_ops, seed=700)
     ops = []

@@ -739,13 +739,6 @@ class PipelineLayer(StoreLayer):
         return self._traced_direct("delete", 1,
                                    lambda: self.inner.delete(key))
 
-    # ----------------------------------------------------------- metering
-    def meter_totals(self):
-        return self.inner.meter_totals()
-
-    def reset_meters(self) -> None:
-        self.inner.reset_meters()
-
 
 def _as_array(x):
     if x is None or isinstance(x, np.ndarray):

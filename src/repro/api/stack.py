@@ -54,13 +54,28 @@ from repro.core.hashing import split_u64
 
 class StoreLayer:
     """Base middleware: wraps an inner KVStore, delegates what it doesn't
-    override (``spec``, ``engine``, ``meter_totals``, ...)."""
+    override (``engine``, ``meter``, ...).
+
+    The members :class:`repro.api.protocol.KVStore` lists are declared
+    here rather than left to ``__getattr__``: ``isinstance`` against a
+    runtime-checkable protocol looks members up with
+    ``inspect.getattr_static``, which never calls ``__getattr__``."""
 
     def __init__(self, inner):
         self.inner = inner
 
     def __getattr__(self, name):
         return getattr(self.inner, name)
+
+    @property
+    def spec(self):
+        return self.inner.spec
+
+    def meter_totals(self):
+        return self.inner.meter_totals()
+
+    def reset_meters(self) -> None:
+        self.inner.reset_meters()
 
 
 class RetryLayer(StoreLayer):

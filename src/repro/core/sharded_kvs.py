@@ -44,9 +44,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import ludo, slots
 from repro.core.cn_cache import ShardedCNCache, cache_probe
 from repro.core.hashing import hash64_32, slot_hash, split_u64
@@ -66,7 +66,7 @@ class ShardedKVSState:
     words_a: np.ndarray  # (M, WA)
     words_b: np.ndarray  # (M, WB)
     seeds: np.ndarray  # (M, NB)
-    oth_meta: np.ndarray  # (M, 4) int64: seed_a, seed_b (per-shard retries)
+    oth_meta: np.ndarray  # (M, 4) uint32: seed_a, seed_b (per-shard retries)
     # MN component, range-sharded over 'data': specs P('model', 'data', ...)
     slots_lo: np.ndarray  # (M, NB, 4)
     slots_hi: np.ndarray  # (M, NB, 4)
@@ -144,7 +144,7 @@ def build_sharded(keys: np.ndarray, values: np.ndarray, *, num_shards: int,
         words_a=np.zeros((M, wa_words), np.uint32),
         words_b=np.zeros((M, wb_words), np.uint32),
         seeds=np.zeros((M, nb), np.uint8),
-        oth_meta=np.zeros((M, 4), np.int64),
+        oth_meta=np.zeros((M, 4), np.uint32),
         slots_lo=np.zeros((M, nb, 4), np.uint32),
         slots_hi=np.zeros((M, nb, 4), np.uint32),
         heap_klo=np.full((M, cap), SENT, np.uint32),
@@ -276,10 +276,8 @@ def make_get_fn(mesh: Mesh, st: ShardedKVSState, batch_per_device: int,
     nb, ma, mb = st.num_buckets, st.ma, st.mb
 
     def cn_locate(q_lo, q_hi, words_a, words_b, seeds, oth_meta):
-        seed_a = oth_meta[0].astype(jnp.uint32)
-        seed_b = oth_meta[1].astype(jnp.uint32)
-        ia = hash64_32(q_lo, q_hi, seed_a, jnp) % jnp.uint32(ma)
-        ib = hash64_32(q_lo, q_hi, seed_b, jnp) % jnp.uint32(mb)
+        ia = hash64_32(q_lo, q_hi, oth_meta[0], jnp) % jnp.uint32(ma)
+        ib = hash64_32(q_lo, q_hi, oth_meta[1], jnp) % jnp.uint32(mb)
         bit_a = (words_a[(ia >> jnp.uint32(5)).astype(jnp.int32)]
                  >> (ia & jnp.uint32(31))) & jnp.uint32(1)
         bit_b = (words_b[(ib >> jnp.uint32(5)).astype(jnp.int32)]

@@ -8,11 +8,13 @@ per the paper the CN component costs (2.33 + 2/eps) bits/key, so even a
 4M-key shard's locator is ~2.3 MB, comfortably VMEM-resident, while the
 memory-heavy half stays in HBM on the "memory pool" devices.
 
-TPU adaptation notes (DESIGN.md §2): the in-kernel gathers are lane-wise
-dynamic gathers from VMEM (supported on recent TPU generations; validated
-here in interpret mode).  Hash math is uint32 VPU arithmetic — no MXU use,
-this kernel is bandwidth-trivial and compute-tiny, exactly like the CN role
-the paper prescribes.
+TPU status: the TPU v5e compiler refuses this kernel.  Its whole-array
+``jnp.take`` gathers on VMEM refs raise ``NotImplementedError: Only 2D
+gather is supported`` at any table size, and its blocks are 1-D.  It has
+only ever run under the Pallas interpreter, so ``repro.kernels.ops``
+serves the locator with the jnp path on every backend.  Hash math is
+uint32 VPU arithmetic — no MXU use, this kernel is bandwidth-trivial and
+compute-tiny, exactly like the CN role the paper prescribes.
 """
 
 from __future__ import annotations

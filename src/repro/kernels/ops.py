@@ -1,10 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Backend dispatch: on TPU the Pallas kernels run natively; everywhere else
-(CPU CI, the 512-device dry-run) the pure-jnp oracles from ``ref.py`` are
-used — same signature, same outputs.  ``interpret=True`` forces the Pallas
-path under the Pallas interpreter (the correctness-validation mode used by
-the kernel test sweeps).
+Backend dispatch: on TPU the Pallas kernels run natively, except the
+Ludo locator, which the chip's compiler refuses (see :func:`ludo_lookup`);
+everywhere else (CPU CI, the 512-device dry-run) the pure-jnp oracles from
+``ref.py`` are used — same signature, same outputs.  ``mode='pallas'`` off
+the chip runs the kernel under the Pallas interpreter (the
+correctness-validation mode used by the kernel test sweeps).
 """
 
 from __future__ import annotations
@@ -34,10 +35,14 @@ def _round_up(x: int, m: int) -> int:
 def ludo_lookup(key_lo, key_hi, words_a, words_b, seeds, meta, *,
                 mode: str = "auto", block: int = 1024):
     """Batched CN locator. ``meta`` = dict(ma, mb, nb, seed_a, seed_b,
-    seed_ba, seed_bb). mode: 'auto' | 'pallas' | 'ref'."""
-    use_pallas = mode == "pallas" or (mode == "auto" and on_tpu())
-    if not use_pallas:
-        from repro.core.ludo import SEED_BUCKET_A, SEED_BUCKET_B  # noqa: F401
+    seed_ba, seed_bb). mode: 'auto' | 'pallas' | 'ref'.
+
+    ``auto`` runs the jnp locator on every backend, TPU included: the
+    chip's compiler refuses the Pallas kernel (its whole-array gathers
+    from VMEM raise ``NotImplementedError: Only 2D gather is supported``).
+    ``pallas`` on a TPU therefore raises that error; elsewhere it runs the
+    kernel under the Pallas interpreter."""
+    if mode != "pallas":
         return ref.ludo_lookup_ref(
             key_lo, key_hi, words_a, words_b, seeds,
             ma=meta["ma"], mb=meta["mb"], nb=meta["nb"],

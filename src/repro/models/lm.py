@@ -33,6 +33,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -40,7 +41,6 @@ from repro.models import attention as att
 from repro.models import mamba as mam
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
-from repro.compat import shard_map
 from repro.models.common import KeyGen, rms_norm, silu
 
 

@@ -21,8 +21,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.models.common import silu
 
 

@@ -19,9 +19,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import TrainConfig
 from repro.models.lm import LM
 from repro.train.optimizer import TrainState, adamw_update

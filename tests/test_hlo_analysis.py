@@ -91,7 +91,7 @@ def test_collective_bytes_multiply_by_trips():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.launch.hlo_analysis import analyse_text
         mesh = jax.make_mesh((4,), ("data",))
         def inner(x):

@@ -152,3 +152,18 @@ def test_ops_dispatch_ref_on_cpu(shard):
     bb, ss = sh.cn.locate(lo, hi)
     np.testing.assert_array_equal(np.asarray(b), bb.astype(np.int32))
     np.testing.assert_array_equal(np.asarray(s), ss.astype(np.int32))
+
+
+def test_ops_ludo_lookup_pallas_mode_interprets_off_chip(shard):
+    """Off the chip ``mode='pallas'`` runs the kernel under the
+    interpreter, padded to its block, and answers as the host locator."""
+    sh, keys = shard
+    lo, hi = split_u64(keys[:1000])
+    b, s = ops.ludo_lookup(jnp.asarray(lo), jnp.asarray(hi),
+                           jnp.asarray(sh.cn.othello.words_a),
+                           jnp.asarray(sh.cn.othello.words_b),
+                           jnp.asarray(sh.cn.seeds), ops.cn_meta_from(sh),
+                           mode="pallas", block=512)
+    bb, ss = sh.cn.locate(lo, hi)
+    np.testing.assert_array_equal(np.asarray(b), bb.astype(np.int32))
+    np.testing.assert_array_equal(np.asarray(s), ss.astype(np.int32))

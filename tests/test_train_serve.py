@@ -259,9 +259,6 @@ def test_int8_pod_gradient_compression_subprocess():
     import os
     import subprocess
     import sys
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        pytest.skip("partial-auto shard_map (manual pod subgroup) aborts in "
-                    "this jax/XLA build: Check failed IsManualSubgroup()")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.abspath(
