@@ -31,6 +31,8 @@ import typing
 
 import numpy as np
 
+from repro.obs import wall
+
 
 class UnsupportedOperation(RuntimeError):
     """The store kind cannot serve this op (e.g. no MN kernel on RACE)."""
@@ -94,9 +96,12 @@ class OpResult:
 def pack_result(v_lo, v_hi, match) -> OpResult:
     """Combine an engine's native ``(v_lo, v_hi, match)`` triple (numpy or
     jax arrays) into a host OpResult."""
-    v_lo = np.asarray(v_lo).astype(np.uint64)
-    v_hi = np.asarray(v_hi).astype(np.uint64)
-    found = np.asarray(match, dtype=bool)
+    with wall.device_span(wall.GET_FETCH, v_lo, v_hi, match):
+        v_lo, v_hi, match = (np.asarray(v_lo), np.asarray(v_hi),
+                             np.asarray(match))
+    v_lo = v_lo.astype(np.uint64)
+    v_hi = v_hi.astype(np.uint64)
+    found = match.astype(bool, copy=False)
     values = np.where(found, (v_hi << np.uint64(32)) | v_lo, np.uint64(0))
     return OpResult(values=values, found=found)
 

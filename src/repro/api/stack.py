@@ -50,6 +50,7 @@ import numpy as np
 from repro.api.protocol import OpResult
 from repro.core.cn_cache import CNKeyCache
 from repro.core.hashing import split_u64
+from repro.obs import wall
 
 
 class StoreLayer:
@@ -209,19 +210,20 @@ class CNCacheLayer(StoreLayer):
                   resolve_makeup: bool | None = None) -> OpResult:
         keys = np.asarray(keys, dtype=np.uint64)
         h_lo, h_hi = split_u64(keys)
-        hit, neg, c_vlo, c_vhi = self.cache.probe_batch(h_lo, h_hi)
-        # charge the savings the avoided Get would have cost on THIS
-        # kind's wire (the adapter declares its protocol's shape)
-        meter = self.inner.meter
-        meter.add_cache_hit(int(hit.sum()), **self.inner.cache_hit_savings)
-        meter.add_cache_hit(int(neg.sum()), neg=True,
-                            **self.inner.cache_neg_savings)
-        if self.hub is not None:
-            n_hit, n_neg = int(hit.sum()), int(neg.sum())
-            n_miss = len(keys) - n_hit - n_neg
-            self.hub.on_cache(n_hit, n_neg, n_miss)
-            self.hub.annotate(cache_hits=n_hit, cache_neg_hits=n_neg,
-                              cache_misses=n_miss)
+        with wall.span(wall.CACHE_PROBE):
+            hit, neg, c_vlo, c_vhi = self.cache.probe_batch(h_lo, h_hi)
+            # charge the savings the avoided Get would have cost on THIS
+            # kind's wire (the adapter declares its protocol's shape)
+            meter = self.inner.meter
+            meter.add_cache_hit(int(hit.sum()), **self.inner.cache_hit_savings)
+            meter.add_cache_hit(int(neg.sum()), neg=True,
+                                **self.inner.cache_neg_savings)
+            if self.hub is not None:
+                n_hit, n_neg = int(hit.sum()), int(neg.sum())
+                n_miss = len(keys) - n_hit - n_neg
+                self.hub.on_cache(n_hit, n_neg, n_miss)
+                self.hub.annotate(cache_hits=n_hit, cache_neg_hits=n_neg,
+                                  cache_misses=n_miss)
         values = ((np.asarray(c_vhi, np.uint64) << np.uint64(32))
                   | np.asarray(c_vlo, np.uint64))
         found = hit.copy()
@@ -248,17 +250,20 @@ class CNCacheLayer(StoreLayer):
                 statuses = tuple(next(mi) if m else "ok" for m in miss)
                 learned = hit | neg
                 if learned.any():
-                    self.cache.observe_batch(
-                        h_lo[learned], h_hi[learned],
-                        (values[learned] & np.uint64(0xFFFFFFFF)
-                         ).astype(np.uint32),
-                        (values[learned] >> np.uint64(32)).astype(np.uint32),
-                        found[learned], hit[learned], neg[learned])
+                    with wall.span(wall.CACHE_OBSERVE):
+                        self.cache.observe_batch(
+                            h_lo[learned], h_hi[learned],
+                            (values[learned] & np.uint64(0xFFFFFFFF)
+                             ).astype(np.uint32),
+                            (values[learned] >> np.uint64(32)
+                             ).astype(np.uint32),
+                            found[learned], hit[learned], neg[learned])
                 return OpResult(values=values, found=found,
                                 statuses=statuses)
-        self.cache.observe_batch(
-            h_lo, h_hi, (values & np.uint64(0xFFFFFFFF)).astype(np.uint32),
-            (values >> np.uint64(32)).astype(np.uint32), found, hit, neg)
+        with wall.span(wall.CACHE_OBSERVE):
+            self.cache.observe_batch(
+                h_lo, h_hi, (values & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+                (values >> np.uint64(32)).astype(np.uint32), found, hit, neg)
         return OpResult(values=values, found=found)
 
     # ----------------------------------------------------------- mutations
@@ -289,9 +294,10 @@ class CNCacheLayer(StoreLayer):
 
     def update_batch(self, keys, values) -> OpResult:
         res = self.inner.update_batch(keys, values)
-        for k, v, ok in zip(keys, values, res.found):
-            if ok:
-                self.cache.note_update(int(k), int(v))
+        with wall.span(wall.CACHE_NOTE):
+            for k, v, ok in zip(keys, values, res.found):
+                if ok:
+                    self.cache.note_update(int(k), int(v))
         return res
 
     def delete_batch(self, keys) -> OpResult:

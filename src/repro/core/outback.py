@@ -42,6 +42,7 @@ from repro.core.hashing import (fingerprint6, fingerprint6_int, slot_hash,
                                 slot_hash_int, split_u64)
 from repro.core.meter import MSG_BYTES, CommMeter
 from repro.core.overflow import OverflowCache
+from repro.obs import wall
 
 GET_REQ_BYTES = 8  # ind_bucket + ind_slot, packed (padded to MSG_BYTES on wire)
 KV_BLOCK_BYTES = 32  # klen(8)+vlen(8)+key(8)+value(8) — the paper's workloads
@@ -538,16 +539,20 @@ class OutbackShard:
         return ok
 
     # ------------------------------------------------- batched (device) path
+    def _cn_host(self) -> tuple:
+        oth = self.cn.othello
+        return oth.words_a, oth.words_b, self.cn.seeds
+
+    def _mn_host(self) -> tuple:
+        return (self.slots_lo, self.slots_hi, self.heap_klo, self.heap_khi,
+                self.heap_vlo, self.heap_vhi)
+
     def cn_arrays(self, xp=np):
         """The CN-cached arrays, converted for the target namespace."""
-        oth = self.cn.othello
-        return (xp.asarray(oth.words_a), xp.asarray(oth.words_b),
-                xp.asarray(self.cn.seeds))
+        return tuple(xp.asarray(a) for a in self._cn_host())
 
     def mn_arrays(self, xp=np):
-        return (xp.asarray(self.slots_lo), xp.asarray(self.slots_hi),
-                xp.asarray(self.heap_klo), xp.asarray(self.heap_khi),
-                xp.asarray(self.heap_vlo), xp.asarray(self.heap_vhi))
+        return tuple(xp.asarray(a) for a in self._mn_host())
 
     def get_batch(self, keys: np.ndarray, xp=np, cn=None, mn=None,
                   resolve_makeup: bool | None = None):
@@ -566,14 +571,21 @@ class OutbackShard:
         """
         keys = np.asarray(keys, dtype=np.uint64)
         h_lo, h_hi = split_u64(keys)
-        cn = self.cn_arrays(xp) if cn is None else cn
-        mn = self.mn_arrays(xp) if mn is None else mn
+        sent = ((h_lo, h_hi) + (self._cn_host() if cn is None else ())
+                + (self._mn_host() if mn is None else ()))
+        with wall.span(wall.GET_UPLOAD):
+            d_lo, d_hi = xp.asarray(h_lo), xp.asarray(h_hi)
+            cn = self.cn_arrays(xp) if cn is None else cn
+            mn = self.mn_arrays(xp) if mn is None else mn
+        if xp is not np:
+            wall.count(wall.H2D_BYTES, sum(a.nbytes for a in sent))
         n = int(keys.shape[0])
         if resolve_makeup is None:
             resolve_makeup = self.cn_cache is not None
         if self.cn_cache is None:
-            out = outback_get_batch(xp.asarray(h_lo), xp.asarray(h_hi), cn,
-                                    mn, self.cn.othello, self.cn.num_buckets, xp)
+            with wall.span(wall.GET_DISPATCH):
+                out = outback_get_batch(d_lo, d_hi, cn, mn, self.cn.othello,
+                                        self.cn.num_buckets, xp)
             self.meter.add(n, rts=1, req=GET_REQ_BYTES, resp=KV_BLOCK_BYTES,
                            cn_hash=5, cn_cmp=1, mn_reads=2)
             if resolve_makeup:
@@ -601,23 +613,22 @@ class OutbackShard:
                                         hit, neg)
             return v_lo, v_hi, match
         # device path: full-batch kernel keeps shapes static for jit; hit
-        # lanes are merged over the (discarded) MN result
-        v_lo, v_hi, match = outback_get_batch(
-            xp.asarray(h_lo), xp.asarray(h_hi), cn, mn, self.cn.othello,
-            self.cn.num_buckets, xp)
+        # lanes are merged on the host over the (discarded) MN result,
+        # which the cache has to observe there anyway
+        with wall.span(wall.GET_DISPATCH):
+            v_lo, v_hi, match = outback_get_batch(
+                d_lo, d_hi, cn, mn, self.cn.othello, self.cn.num_buckets, xp)
         if resolve_makeup:
             # only true misses take the makeup trip: cached and known-absent
             # lanes already have their answer
             v_lo, v_hi, match = self._resolve_makeups(
                 keys, v_lo, v_hi, match, xp=xp, skip=hit | neg)
-        self.cn_cache.observe_batch(h_lo, h_hi, np.asarray(v_lo),
-                                    np.asarray(v_hi), np.asarray(match),
-                                    hit, neg)
-        hit_x = xp.asarray(hit)
-        v_lo = xp.where(hit_x, xp.asarray(c_vlo), v_lo)
-        v_hi = xp.where(hit_x, xp.asarray(c_vhi), v_hi)
-        match = xp.where(hit_x, True, match)
-        return v_lo, v_hi, match
+        with wall.device_span(wall.GET_FETCH, v_lo, v_hi, match):
+            v_lo, v_hi, match = (np.asarray(v_lo), np.asarray(v_hi),
+                                 np.asarray(match))
+        self.cn_cache.observe_batch(h_lo, h_hi, v_lo, v_hi, match, hit, neg)
+        return (np.where(hit, c_vlo, v_lo), np.where(hit, c_vhi, v_hi),
+                hit | match)
 
     def _resolve_makeups(self, keys: np.ndarray, v_lo, v_hi, match, *,
                          xp=np, skip=None):
@@ -634,15 +645,31 @@ class OutbackShard:
         continuation attachment — proven lane-identical against
         ``_resolve_makeups_reference`` in ``tests/test_makeup_batch.py``.
         """
-        pending = ~np.asarray(match)
+        # one readback of the whole answer: the caller reads it on the host
+        # anyway, so with no lane to resolve it gets the host arrays back
+        with wall.device_span(wall.GET_FETCH, v_lo, v_hi, match):
+            v_lo, v_hi, match = (np.asarray(v_lo), np.asarray(v_hi),
+                                 np.asarray(match))
+        pending = ~match
         if skip is not None:
             pending &= ~np.asarray(skip)
         idx = np.nonzero(pending)[0]
+        wall.count(wall.MAKEUP_LANES, idx.size)
         if idx.size == 0:
             return v_lo, v_hi, match
-        v_lo = np.asarray(v_lo).copy()
-        v_hi = np.asarray(v_hi).copy()
-        match = np.asarray(match).copy()
+        with wall.span(wall.GET_MAKEUP):
+            v_lo, v_hi, match = self._makeup_lanes(keys, idx, v_lo.copy(),
+                                                   v_hi.copy(), match.copy())
+        with wall.span(wall.GET_UPLOAD):
+            out = xp.asarray(v_lo), xp.asarray(v_hi), xp.asarray(match)
+        if xp is not np:
+            wall.count(wall.H2D_BYTES,
+                       v_lo.nbytes + v_hi.nbytes + match.nbytes)
+        return out
+
+    def _makeup_lanes(self, keys: np.ndarray, idx: np.ndarray, v_lo, v_hi,
+                      match):
+        """Resolve lanes ``idx`` of a host answer in place and return it."""
         lo, hi = split_u64(np.asarray(keys, np.uint64)[idx])
         b, _ = self.cn.locate(lo, hi)
         b = b.astype(np.int64)
@@ -682,7 +709,7 @@ class OutbackShard:
         v_lo[hit_idx] = self.heap_vlo[a]
         v_hi[hit_idx] = self.heap_vhi[a]
         match[hit_idx] = True
-        return xp.asarray(v_lo), xp.asarray(v_hi), xp.asarray(match)
+        return v_lo, v_hi, match
 
     def _resolve_makeups_reference(self, keys: np.ndarray, v_lo, v_hi, match,
                                    *, xp=np, skip=None):

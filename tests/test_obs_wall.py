@@ -1,0 +1,187 @@
+"""Wall-clock spans and counters of the served Get and update path
+(``repro.obs.wall``): where each span lands in a profiler trace, what the
+counters count, and that neither changes an answer or a meter."""
+
+import pathlib
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.profiler import ProfileData, TraceAnnotation
+
+from repro.api import StoreSpec, open_store
+from repro.core.hashing import splitmix64
+from repro.core.outback import OutbackShard
+from repro.core.store import make_uniform_keys
+from repro.obs import wall
+
+N = 4000
+CALLER = "client.call"
+KEYS = make_uniform_keys(N, 7)
+FRESH = splitmix64(np.arange(1, 600, dtype=np.uint64) + np.uint64(9 << 40))
+ABSENT = splitmix64(np.arange(1, 64, dtype=np.uint64) + np.uint64(1 << 45))
+# slot residents, overflow residents of the pressured stores, absent keys
+QUERIES = np.concatenate([KEYS[:800], FRESH[:400], ABSENT])
+
+
+def _queries(cache: bool):
+    """Keys that all sit in slots for the plain store (no lane for the
+    Makeup-Get), every case of it for the pressured one."""
+    return QUERIES if cache else KEYS[:1024]
+
+
+def _store(cache: bool):
+    """``plain``: a fresh store, no key in overflow.  ``cache``: a CN cache
+    in front of a store driven past ``s_slow`` (as in
+    tests/test_makeup_batch.py), so misses take the Makeup-Get."""
+    if not cache:
+        return open_store(StoreSpec("outback"), KEYS, splitmix64(KEYS))
+    st = open_store(StoreSpec("outback", load_factor=0.95, rng_seed=3,
+                              cache_budget_bytes=1 << 12,
+                              params={"overflow_frac": 0.05}),
+                    KEYS, splitmix64(KEYS))
+    for k in FRESH:
+        if st.engine.must_stop():
+            break
+        st.insert(int(k), int(splitmix64(np.uint64([k]))[0]))
+    return st
+
+
+def _pressured_shard():
+    sh = OutbackShard(KEYS, splitmix64(KEYS), load_factor=0.95,
+                      overflow_frac=0.05, rng_seed=3)
+    for k in FRESH:
+        if sh.must_stop():
+            break
+        sh.insert(int(k), int(splitmix64(np.uint64([k]))[0]))
+    return sh
+
+
+def _traced(trace_dir, call):
+    """Run ``call`` inside a ``CALLER`` span under a profiler trace; return
+    its result, the caller's (start, end) and the program's spans."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        with TraceAnnotation(CALLER):
+            out = call()
+    finally:
+        jax.profiler.stop_trace()
+    xplane = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        for line in plane.lines:
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for e in line.events]
+            outer = [(s, e) for n, s, e in events if n == CALLER]
+            if outer:
+                spans = sorted((ev for ev in events
+                                if ev[0].startswith("repro.")),
+                               key=lambda ev: ev[1])
+                return out, outer[0], spans
+    raise AssertionError("no caller span in the trace")
+
+
+def _upload_bytes(engine, lanes: int) -> int:
+    host = engine._cn_host() + engine._mn_host()
+    return sum(a.nbytes for a in host) + 8 * lanes
+
+
+GET_SPANS = [wall.GET_UPLOAD, wall.GET_DISPATCH, wall.GET_FETCH]
+
+
+@pytest.mark.parametrize("case,want", [
+    ("plain", GET_SPANS),
+    ("cache", [wall.CACHE_PROBE] + GET_SPANS
+     + [wall.GET_MAKEUP, wall.GET_UPLOAD, wall.GET_FETCH,
+        wall.CACHE_OBSERVE]),
+    ("update", [wall.CACHE_NOTE]),
+])
+def test_spans_of_one_call(tmp_path, case, want):
+    st = _store(cache=case != "plain")
+    q = _queries(cache=case != "plain")
+    st.get_batch(q[:64], xp=jnp)  # compile outside the trace
+    if case == "update":
+        def call():
+            return st.update_batch(KEYS[:64], splitmix64(KEYS[:64]))
+    else:
+        def call():
+            return st.get_batch(q, xp=jnp)
+    _, (a, b), spans = _traced(tmp_path, call)
+    assert [n for n, _, _ in spans] == want
+    # siblings on the calling thread: inside the caller, none overlapping
+    assert all(a <= s <= e <= b for _, s, e in spans)
+    assert all(e0 <= s1 for (_, _, e0), (_, s1, _) in zip(spans, spans[1:]))
+
+
+@pytest.mark.parametrize("cache", [False, True], ids=["plain", "cache"])
+def test_h2d_bytes_count_every_upload(cache):
+    st = _store(cache)
+    before = wall.totals().get(wall.H2D_BYTES, 0)
+    q = _queries(cache)
+    res = st.get_batch(q, xp=jnp)
+    grown = wall.totals()[wall.H2D_BYTES] - before
+    sent = q.shape[0] - res.cache_hits - res.cache_neg_hits
+    want = _upload_bytes(st.engine, sent)
+    assert bool(res.makeups) == cache
+    if cache:
+        want += 9 * sent  # Makeup answers re-uploaded: v_lo, v_hi, match
+    assert grown == want
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
+def test_makeup_lanes_match_the_reference(xp):
+    a, b = _pressured_shard(), _pressured_shard()
+    raw = b.get_batch(QUERIES, resolve_makeup=False)
+    pending = ~np.asarray(raw[2])
+    assert pending.sum() > 200, "workload sized for a real makeup wave"
+    want = b._resolve_makeups_reference(QUERIES, *raw, xp=np)
+    before = wall.totals().get(wall.MAKEUP_LANES, 0)
+    got = a.get_batch(QUERIES, xp=xp, resolve_makeup=True)
+    assert wall.totals()[wall.MAKEUP_LANES] - before == pending.sum()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("cache", [False, True], ids=["plain", "cache"])
+def test_profiler_changes_no_answer_or_meter(tmp_path, cache):
+    q = _queries(cache)
+
+    def session(st):
+        got = [st.get_batch(q, xp=jnp)]
+        got.append(st.update_batch(KEYS[:64], splitmix64(KEYS[:64] + 1)))
+        got.append(st.get_batch(q, xp=jnp))
+        return got
+
+    plain, traced = _store(cache), _store(cache)
+    want = session(plain)
+    got, _, spans = _traced(tmp_path, lambda: session(traced))
+    assert spans
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.values, w.values)
+        np.testing.assert_array_equal(g.found, w.found)
+    assert traced.meter_totals().snapshot() == plain.meter_totals().snapshot()
+
+
+@pytest.mark.parametrize("ring,t0,want", [
+    (16, 0, 10),  # nothing dropped: the counter was 0 before its samples
+    (16, 3, 7),
+    (4, 7, 3),  # the samples the interval needs are still in the ring
+    (4, 5, None),  # the sample at or before t0 has been dropped
+    (4, 0, None),
+])
+def test_delta_over_a_bounded_ring(monkeypatch, ring, t0, want):
+    clock = iter(range(1, 100))
+    monkeypatch.setattr(wall, "RING_SAMPLES", ring)
+    fake = types.SimpleNamespace(perf_counter=lambda: next(clock))
+    monkeypatch.setattr(wall, "time", fake)
+    monkeypatch.setattr(wall, "_rings", {})
+    monkeypatch.setattr(wall, "_totals", {})
+    monkeypatch.setattr(wall, "_dropped", set())
+    for _ in range(10):
+        wall.count("test.ring", 1)  # samples (1, 1) ... (10, 10)
+    assert wall.delta("test.ring", t0, 50) == want
+    assert wall.totals() == {"test.ring": 10}
