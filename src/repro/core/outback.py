@@ -107,6 +107,7 @@ class OutbackShard:
         build = ludo.build(lo, hi, load_factor=load_factor, rng_seed=rng_seed,
                            num_buckets=num_buckets, oth_ma=oth_ma, oth_mb=oth_mb)
         self.load_factor = load_factor
+        self._dev: dict[str, tuple] = {}  # device copies, see _device_copy
         self.cn = build.cn  # CN-cached locator+seeds (the decoupled half)
         nb = build.cn.num_buckets
 
@@ -230,6 +231,7 @@ class OutbackShard:
                 # — trusted only under a live MN lease (docs/FAILURE_MODEL.md).
                 if self.lease is not None:
                     self.lease.on_seed_refresh(self)
+                self._cn_written()
                 self.cn.seeds[bucket] = self.seeds_mn[bucket]
                 val = (int(self.heap_vhi[a]) << 32) | int(self.heap_vlo[a])
                 return GetResult(val, 2, True)
@@ -259,6 +261,7 @@ class OutbackShard:
         precomputes ``s``/``fp`` vectorised; the scalar path derives them
         here — either way the protocol walk and accounting are this one
         code path."""
+        self._mn_written()  # every outcome writes the heap (or grows it)
         self.meter.add(rts=1, req=8 + KV_BLOCK_BYTES, resp=8,
                        cn_hash=4, mn_hash=1, mn_writes=1)
         # MN: seeded slot with the *latest* seed.
@@ -327,6 +330,7 @@ class OutbackShard:
                 self.slots_lo[b, int(new_slots[-1])] = s_lo
                 self.slots_hi[b, int(new_slots[-1])] = s_hi
                 self.seeds_mn[b] = new_seed
+                self._cn_written()
                 self.cn.seeds[b] = new_seed  # returned in the RPC response
                 self.n_keys += 1
                 return "reseed"
@@ -358,6 +362,7 @@ class OutbackShard:
         if int(f["len"]) != 0:
             a = int(f["addr_lo"])
             if (int(self.heap_klo[a]), int(self.heap_khi[a])) == (lo, hi):
+                self._mn_written()
                 self.heap_vlo[a] = value & 0xFFFFFFFF
                 self.heap_vhi[a] = (value >> 32) & 0xFFFFFFFF
                 self.meter.add(0, mn_writes=1, attach=True)
@@ -366,6 +371,7 @@ class OutbackShard:
             addr, probes = self.overflow.lookup(lo, hi)
             self.meter.add(0, mn_hash=1, mn_cmp=probes, mn_reads=probes, attach=True)
             if addr is not None:
+                self._mn_written()
                 self.heap_vlo[addr] = value & 0xFFFFFFFF
                 self.heap_vhi[addr] = (value >> 32) & 0xFFFFFFFF
                 self.meter.add(0, mn_writes=1, attach=True)
@@ -378,6 +384,8 @@ class OutbackShard:
             a = int(ft["addr_lo"])
             self.meter.add(0, mn_cmp=1, mn_reads=1, attach=True)
             if (int(self.heap_klo[a]), int(self.heap_khi[a])) == (lo, hi):
+                self._mn_written()
+                self._cn_written()
                 self.heap_vlo[a] = value & 0xFFFFFFFF
                 self.heap_vhi[a] = (value >> 32) & 0xFFFFFFFF
                 self.meter.add(0, mn_writes=1, attach=True)
@@ -406,6 +414,7 @@ class OutbackShard:
             a = int(f["addr_lo"])
             if (int(self.heap_klo[a]), int(self.heap_khi[a])) == (lo, hi):
                 cache_bit = np.uint32(int(f["cache"]) << slots.CACHE_SHIFT)
+                self._mn_written()
                 self.slots_lo[b, s] = 0
                 self.slots_hi[b, s] = cache_bit  # keep cache hint
                 self.meter.add(0, mn_writes=1, attach=True)
@@ -490,6 +499,7 @@ class OutbackShard:
         ok = fast.copy()
         n_fast = int(fast.sum())
         if n_fast:
+            self._mn_written()
             a = addr[fast]  # duplicate keys: last lane wins, as in order
             self.heap_vlo[a] = vlo[fast]
             self.heap_vhi[a] = vhi[fast]
@@ -524,6 +534,7 @@ class OutbackShard:
         ok = fast.copy()
         n_fast = int(fast.sum())
         if n_fast:
+            self._mn_written()
             bf, sf = b[fast], s[fast]
             cache_bits = self.slots_hi[bf, sf] & np.uint32(1 << slots.CACHE_SHIFT)
             self.slots_lo[bf, sf] = 0
@@ -547,12 +558,38 @@ class OutbackShard:
         return (self.slots_lo, self.slots_hi, self.heap_klo, self.heap_khi,
                 self.heap_vlo, self.heap_vhi)
 
+    # The device copies of the two halves stay in HBM between device Gets
+    # until a write changes their host arrays: each writer calls
+    # _cn_written or _mn_written first, which drops the copy, so the next
+    # device call uploads afresh and at most one copy of a half is kept.
+    # Only cn.seeds changes the CN half (the Othello words are fixed at
+    # build).  The heap helpers are reached only from the constructor
+    # (before any copy) and _insert_located (which marks); the overflow
+    # cache lives on the host and is no part of either half.
+    def _cn_written(self) -> None:
+        self._dev.pop("cn", None)
+
+    def _mn_written(self) -> None:
+        self._dev.pop("mn", None)
+
+    def _device_copy(self, half: str, xp) -> tuple[tuple, int]:
+        """(the ``half`` ("cn" or "mn") arrays in ``xp``, bytes handed to
+        the device for them on this call)."""
+        host = self._cn_host() if half == "cn" else self._mn_host()
+        if xp is np:
+            return host, 0
+        if half in self._dev:
+            return self._dev[half], 0
+        kept = self._dev[half] = tuple(xp.asarray(a) for a in host)
+        return kept, sum(a.nbytes for a in host)
+
     def cn_arrays(self, xp=np):
-        """The CN-cached arrays, converted for the target namespace."""
-        return tuple(xp.asarray(a) for a in self._cn_host())
+        """The CN-cached arrays, converted for the target namespace; on a
+        device, the copy kept since the last write to them."""
+        return self._device_copy("cn", xp)[0]
 
     def mn_arrays(self, xp=np):
-        return tuple(xp.asarray(a) for a in self._mn_host())
+        return self._device_copy("mn", xp)[0]
 
     def get_batch(self, keys: np.ndarray, xp=np, cn=None, mn=None,
                   resolve_makeup: bool | None = None):
@@ -571,14 +608,18 @@ class OutbackShard:
         """
         keys = np.asarray(keys, dtype=np.uint64)
         h_lo, h_hi = split_u64(keys)
-        sent = ((h_lo, h_hi) + (self._cn_host() if cn is None else ())
-                + (self._mn_host() if mn is None else ()))
+        cn_sent = mn_sent = 0
         with wall.span(wall.GET_UPLOAD):
             d_lo, d_hi = xp.asarray(h_lo), xp.asarray(h_hi)
-            cn = self.cn_arrays(xp) if cn is None else cn
-            mn = self.mn_arrays(xp) if mn is None else mn
+            if cn is None:
+                cn, cn_sent = self._device_copy("cn", xp)
+            if mn is None:
+                mn, mn_sent = self._device_copy("mn", xp)
         if xp is not np:
-            wall.count(wall.H2D_BYTES, sum(a.nbytes for a in sent))
+            wall.count(wall.H2D_BYTES,
+                       h_lo.nbytes + h_hi.nbytes + cn_sent + mn_sent)
+            if mn_sent:
+                wall.count(wall.MN_UPLOADS, 1)
         n = int(keys.shape[0])
         if resolve_makeup is None:
             resolve_makeup = self.cn_cache is not None
@@ -702,6 +743,7 @@ class OutbackShard:
             # — trusted only under a live MN lease (docs/FAILURE_MODEL.md)
             if self.lease is not None:
                 self.lease.on_seed_refresh(self)
+            self._cn_written()
             bb = b[any_s]
             self.cn.seeds[bb] = self.seeds_mn[bb]
         hit_idx = idx[ok]
@@ -768,6 +810,7 @@ class OutbackShard:
         if state["slots_lo"].shape != self.slots_lo.shape:
             raise ValueError("bucket-count mismatch: replicas must be built "
                              "from the same spec")
+        self._mn_written()
         self.slots_lo = state["slots_lo"].copy()
         self.slots_hi = state["slots_hi"].copy()
         self.seeds_mn = state["seeds_mn"].copy()
@@ -795,6 +838,7 @@ class OutbackShard:
         a §4.4 split and must re-materialise whole tables."""
         t = cls.__new__(cls)
         t.load_factor = load_factor
+        t._dev = {}
         t.cn = cn
         t.slots_lo = mn_state["slots_lo"].copy()
         t.slots_hi = mn_state["slots_hi"].copy()

@@ -36,6 +36,7 @@ CACHE_PROBE = "repro.cache.probe"
 CACHE_OBSERVE = "repro.cache.observe"
 CACHE_NOTE = "repro.cache.note"
 H2D_BYTES = "get.h2d_bytes"
+MN_UPLOADS = "get.mn_uploads"
 MAKEUP_LANES = "get.makeup_lanes"
 
 RING_SAMPLES = 1 << 16  # samples kept per counter

@@ -132,6 +132,59 @@ def test_h2d_bytes_count_every_upload(cache):
     assert grown == want
 
 
+def _stale_seed_shard():
+    """A replica that installed the MN half of a twin whose insert re-seeded
+    a bucket: a Makeup-Get will refresh its CN seeds."""
+    a, b = (OutbackShard(KEYS, splitmix64(KEYS), load_factor=0.95,
+                         rng_seed=3) for _ in range(2))
+    for k in FRESH:
+        if b.insert(int(k), int(splitmix64(np.uint64([k]))[0])) == "reseed":
+            break
+    a.install_mn_state(b.mn_state())
+    return a
+
+
+def _nbytes(arrays) -> int:
+    return sum(a.nbytes for a in arrays)
+
+
+@pytest.mark.parametrize("write", ["none", "update", "seed refresh"])
+def test_a_resident_store_sends_only_what_changed(write):
+    """After the first device call, a call sends the key halves plus the
+    half (CN or MN) that a write changed since, and counts an MN upload
+    only where it re-sent the MN arrays."""
+    sh = _stale_seed_shard()
+    sh.get_batch(QUERIES, xp=jnp)  # the first call uploads both halves
+    if write == "update":
+        assert sh.update_batch(KEYS[:64], splitmix64(KEYS[:64] + 1)).all()
+    if write == "seed refresh":
+        seeds = sh.cn.seeds.copy()
+        sh.get_batch(QUERIES, xp=np, resolve_makeup=True)
+        assert (sh.cn.seeds != seeds).any()
+    before = wall.totals()
+    sh.get_batch(QUERIES, xp=jnp)
+    grown = {n: wall.totals()[n] - before.get(n, 0)
+             for n in (wall.H2D_BYTES, wall.MN_UPLOADS)}
+    want = 8 * QUERIES.shape[0]
+    want += {"none": 0, "update": _nbytes(sh._mn_host()),
+             "seed refresh": _nbytes(sh._cn_host())}[write]
+    assert grown == {wall.H2D_BYTES: want,
+                     wall.MN_UPLOADS: int(write == "update")}
+
+
+@pytest.mark.parametrize("cache", [False, True], ids=["plain", "cache"])
+def test_the_host_path_makes_no_device_array(cache):
+    st = _store(cache)
+    live = {id(a) for a in jax.live_arrays()}
+    before = wall.totals()
+    st.get_batch(_queries(cache), xp=np)
+    st.engine.get_batch(_queries(cache), xp=np, resolve_makeup=True)
+    assert [a for a in jax.live_arrays() if id(a) not in live] == []
+    assert {n: wall.totals().get(n, 0) for n in (wall.H2D_BYTES,
+                                                 wall.MN_UPLOADS)} == \
+        {n: before.get(n, 0) for n in (wall.H2D_BYTES, wall.MN_UPLOADS)}
+
+
 @pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
 def test_makeup_lanes_match_the_reference(xp):
     a, b = _pressured_shard(), _pressured_shard()
