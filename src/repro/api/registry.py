@@ -30,6 +30,7 @@ Third-party kinds register through :func:`register_store`.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 import json
 import typing
@@ -281,8 +282,9 @@ def open_store(spec: StoreSpec, keys, values, *, transport=None):
     none).
 
     When the spec carries ``replicas > 1`` or a ``faults`` schedule, the
-    factory is invoked once per replica (same spec + seed ⇒ identical
-    twins) and the set is wrapped in a
+    factory builds the store once and every further replica is a deep
+    copy of its host state (see :func:`build_adapter`), and the set is
+    wrapped in a
     :class:`repro.api.replication.ReplicaSetAdapter` driven by one
     :class:`repro.net.faults.FaultPlane`; the stack then inserts its
     :class:`repro.api.stack.RetryLayer` above it.  A replicas-only spec
@@ -327,6 +329,10 @@ def build_adapter(spec: StoreSpec, keys, values, *, transport=None):
     must consult (``None`` when no plane is installed).  ``open_store``
     composes this with :class:`repro.api.stack.CNStack`; ``repro.cluster``
     shares one such adapter (the MN pool) across N per-CN stacks.
+
+    Replicas are built once: the K-1 twins are deep copies of the first
+    adapter's host state, byte-identical to it, each with its own engine
+    and meter; the transport and the spec stay shared.
     """
     reg = spec.validate()
     keys = np.asarray(keys, dtype=np.uint64)
@@ -337,7 +343,7 @@ def build_adapter(spec: StoreSpec, keys, values, *, transport=None):
     adapter = reg.factory(spec, keys, values, transport)
     retry = None
     if spec.replicas > 1 or spec.faults is not None:
-        group = [adapter] + [reg.factory(spec, keys, values, transport)
+        group = [adapter] + [_twin(adapter, transport)
                              for _ in range(spec.replicas - 1)]
         plane = FaultPlane(spec.faults if spec.faults is not None
                            else FaultSchedule(lease_term_ops=0))
@@ -353,6 +359,15 @@ def build_adapter(spec: StoreSpec, keys, values, *, transport=None):
                                     placement=placement)
         retry = plane
     return adapter, retry
+
+
+def _twin(adapter, transport):
+    """A deep copy of a freshly built ``adapter`` that shares its spec
+    and ``transport`` (the meter's sink) with it."""
+    memo = {id(adapter.spec): adapter.spec}
+    if transport is not None:
+        memo[id(transport)] = transport
+    return copy.deepcopy(adapter, memo)
 
 
 def _bind_hub_sinks(adapter, hub) -> None:

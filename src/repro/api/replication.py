@@ -5,9 +5,10 @@ Outback's split design makes replication cheap to reason about: the
 compute-heavy locator lives on the CN, so replicating the store means
 replicating only the **memory-heavy MN half** — slot arrays + ``seeds_mn``,
 the KV heap, and the overflow cache (``OutbackShard.mn_state``).  The
-:class:`ReplicaSetAdapter` here wraps K identically-built engine adapters
-(same spec + rng seed ⇒ identical initial state; engine construction never
-meters, so the trace stays clean) behind the ordinary ``KVStore`` surface:
+:class:`ReplicaSetAdapter` here wraps K identical engine adapters (one
+build, deep-copied into its twins by ``repro.api.registry.build_adapter``;
+engine construction never meters, so the trace stays clean) behind the
+ordinary ``KVStore`` surface:
 
 * **Reads** go to the primary replica only (1 RT, unchanged profile).
 * **Writes** are CN-driven multicast: the CN posts the mutation to every
@@ -26,7 +27,13 @@ meters, so the trace stays clean) behind the ordinary ``KVStore`` surface:
   replica's crash window closes re-installs the full MN image from a live
   replica (``install_mn_state``), charged as one one-sided bulk READ of
   ``mn_state_bytes`` — ownership moves in O(state shipped), not O(ops
-  missed).
+  missed).  The primary is the lowest-indexed live replica, so a
+  restarted replica below the primary takes reads back once synced (the
+  read path then serves what the resync installed).
+* **Device copies**: only the primary's engine keeps arrays on the
+  device.  Every primary switch frees the old primary's copies before
+  the new one uploads its own on its next device Get, so the device
+  never holds two replicas' images.
 * **Leases** gate every use of a replica: the CN renews per
   ``FaultSchedule.lease_term_ops`` (one attached small RT, heartbeat
   style), and failover first waits out the dead primary's lease
@@ -43,11 +50,14 @@ meter fields default to zero.
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
 from repro.api.protocol import OpResult, status_result
 from repro.core.meter import CommMeter, MSG_BYTES
 from repro.net.faults import FaultPlane, _mix64
+from repro.obs import wall
 
 BACKOFF = "backoff"
 UNAVAILABLE = "unavailable"
@@ -138,7 +148,7 @@ class ReplicaPlacement:
 
 
 class ReplicaSetAdapter:
-    """K identically-built adapters behind one ``KVStore`` surface.
+    """K identical adapters behind one ``KVStore`` surface.
 
     Sits where a single engine adapter would in the stack (below the
     retry stage); ``.engine`` resolves to the current primary's engine so
@@ -291,6 +301,9 @@ class ReplicaSetAdapter:
                     and not self.plane.partition_open(cn, i):
                 if self._resync(i):
                     self._needs_resync.discard(i)
+                    if self.placement is None and i < self.primary:
+                        with wall.span(wall.REPLICA_FAILOVER):
+                            self._move_primary(i)   # fail back
         return cn
 
     def _charge_wait(self, wait_us: float) -> None:
@@ -339,14 +352,18 @@ class ReplicaSetAdapter:
                         "placement='twins'")
                 pairs.append((s, src))
                 total += int(src.tables[s].mn_state_bytes())
-            for s, src in pairs:
-                dst.tables[s].install_mn_state(src.tables[s].mn_state())
+            with wall.span(wall.REPLICA_RESYNC):
+                for s, src in pairs:
+                    dst.tables[s].install_mn_state(src.tables[s].mn_state())
             state_bytes = total
         else:
             src = self.replicas[donors[0] if self.primary not in donors
                                 else self.primary].engine
-            dst.install_mn_state(src.mn_state())
+            with wall.span(wall.REPLICA_RESYNC):
+                dst.install_mn_state(src.mn_state())
             state_bytes = int(src.mn_state_bytes())
+        wall.count(wall.REPLICA_RESYNCS, 1)
+        wall.count(wall.REPLICA_RESYNC_BYTES, state_bytes)
         if self.transport is not None:
             self.transport.current_mn = i
         self.replicas[i].meter.add(1, rts=1, req=16, resp=state_bytes,
@@ -383,7 +400,8 @@ class ReplicaSetAdapter:
 
         Waits out the dead primary's lease first (``lease_wait_us`` —
         conservative full drain so no two owners coexist), revokes it,
-        and moves the primary cursor.  The new primary's lease is granted
+        and moves the primary cursor (:meth:`_move_primary`: a crashed
+        memory node's memory is gone).  The new primary's lease is granted
         by the next call's :meth:`_lease_check`.  Returns False when no
         live replica exists (the retry stage keeps backing off).
         """
@@ -391,15 +409,23 @@ class ReplicaSetAdapter:
         if not live:
             return False
         nxt = min(live)
-        if self.plane.schedule.lease_term_ops > 0:
-            self._charge_wait(self.plane.schedule.lease_wait_us)
-        self.plane.lease_revoked(self.primary)
-        self.primary = nxt
+        with wall.span(wall.REPLICA_FAILOVER):
+            if self.plane.schedule.lease_term_ops > 0:
+                self._charge_wait(self.plane.schedule.lease_wait_us)
+            self.plane.lease_revoked(self.primary)
+            self._move_primary(nxt)
+        wall.count(wall.REPLICA_FAILOVERS, 1)
         self._meter.failovers += 1
         if self.hub is not None:
             self.hub.count("replica.failovers")
             self.hub.annotate(failovers=1, failover_to=f"mn{nxt}")
         return True
+
+    def _move_primary(self, i: int) -> None:
+        """Point reads at replica ``i``, freeing the old primary's device
+        copies first: ``i`` uploads its own on its next device Get."""
+        self.replicas[self.primary].engine.drop_device_copies()
+        self.primary = i
 
     # ------------------------------------------------------------ internals
     def _serve_read(self, n: int, call) -> OpResult:
@@ -431,7 +457,9 @@ class ReplicaSetAdapter:
         identically); dead replicas are marked for resync, and so is any
         live replica the calling CN's partition hides — it missed this
         write and must not serve until repaired.  Acknowledged ⇔ applied
-        at ≥ 1 reachable live replica.
+        at ≥ 1 reachable live replica.  Each copy applied at a replica
+        other than the primary runs in a ``repro.replica.fanout`` span:
+        the host cost of K-safety.
         """
         cn = self._pre_call(n)
         usable = [i for i in self._live() if i not in self._needs_resync]
@@ -457,7 +485,9 @@ class ReplicaSetAdapter:
             for i in reach:
                 if self.transport is not None:
                     self.transport.current_mn = i
-                r = call(self.replicas[i])
+                with (wall.span(wall.REPLICA_FANOUT) if i != self.primary
+                      else contextlib.nullcontext()):
+                    r = call(self.replicas[i])
                 if i == reach[0]:
                     res = r
         finally:

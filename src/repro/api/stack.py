@@ -92,7 +92,10 @@ class RetryLayer(StoreLayer):
     rounds are spent it answers degraded — ``"unavailable"`` statuses,
     ``found=False``, no exception, no state change — so callers (and
     pipelined ``OpHandle``s) always resolve.  On the no-fault path the
-    wrap is a pure pass-through: no meter event, no trace event.
+    wrap is a pure pass-through: no meter event, no trace event.  Each
+    retried call runs in a ``repro.retry.backoff`` span (the failover
+    before it in its own span, beside it), and every backoff answer adds
+    its lanes to the ``retry.backoff_lanes`` counter.
     """
 
     def __init__(self, inner, plane, transport=None, hub=None):
@@ -106,6 +109,7 @@ class RetryLayer(StoreLayer):
         res = call()
         if not is_backoff(res):
             return res
+        wall.count(wall.RETRY_BACKOFF_LANES, n)
         sched = self.plane.schedule
         meter = self.inner.meter
         hub = self.hub
@@ -124,9 +128,11 @@ class RetryLayer(StoreLayer):
                     and self.inner.can_failover()):
                 self.inner.failover()
             meter.retries += n
-            res = call()
+            with wall.span(wall.RETRY_BACKOFF):
+                res = call()
             if not is_backoff(res):
                 return res
+            wall.count(wall.RETRY_BACKOFF_LANES, n)
         if hub is not None:
             hub.count("retry.unavailable_lanes", n)
             hub.annotate(unavailable_lanes=n)

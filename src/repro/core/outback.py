@@ -572,6 +572,11 @@ class OutbackShard:
     def _mn_written(self) -> None:
         self._dev.pop("mn", None)
 
+    def drop_device_copies(self) -> None:
+        """Free both halves' device copies (a memory node that crashed
+        lost its memory); the next device Get uploads them afresh."""
+        self._dev.clear()
+
     def _device_copy(self, half: str, xp) -> tuple[tuple, int]:
         """(the ``half`` ("cn" or "mn") arrays in ``xp``, bytes handed to
         the device for them on this call)."""

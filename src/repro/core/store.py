@@ -480,6 +480,11 @@ class OutbackStore:
         self._open_split = None
         self._buffer = []
 
+    def drop_device_copies(self) -> None:
+        """Free every table's device copies (see ``OutbackShard``)."""
+        for t in self.tables:
+            t.drop_device_copies()
+
     def mn_state_bytes(self) -> int:
         """On-wire size of one replica resync (MN half only)."""
         seen, total = set(), 0

@@ -1,4 +1,5 @@
-"""Wall-clock spans and counters of the served Get and update path.
+"""Wall-clock spans and counters of the served Get and update path, and of
+the replica set and retry stage around it.
 
 The :class:`~repro.obs.hub.TelemetryHub` keys everything to the op clock
 and simulated microseconds, so its exports are bit-identical across seeded
@@ -35,9 +36,17 @@ GET_MAKEUP = "repro.get.makeup"
 CACHE_PROBE = "repro.cache.probe"
 CACHE_OBSERVE = "repro.cache.observe"
 CACHE_NOTE = "repro.cache.note"
+REPLICA_FANOUT = "repro.replica.fanout"
+REPLICA_FAILOVER = "repro.replica.failover"
+REPLICA_RESYNC = "repro.replica.resync"
+RETRY_BACKOFF = "repro.retry.backoff"
 H2D_BYTES = "get.h2d_bytes"
 MN_UPLOADS = "get.mn_uploads"
 MAKEUP_LANES = "get.makeup_lanes"
+REPLICA_FAILOVERS = "replica.failovers"
+REPLICA_RESYNCS = "replica.resyncs"
+REPLICA_RESYNC_BYTES = "replica.resync_bytes"
+RETRY_BACKOFF_LANES = "retry.backoff_lanes"
 
 RING_SAMPLES = 1 << 16  # samples kept per counter
 

@@ -238,3 +238,107 @@ def test_delta_over_a_bounded_ring(monkeypatch, ring, t0, want):
         wall.count("test.ring", 1)  # samples (1, 1) ... (10, 10)
     assert wall.delta("test.ring", t0, 50) == want
     assert wall.totals() == {"test.ring": 10}
+
+
+# A replicated store through one scripted crash of its primary: rounds of
+# R_GETS Gets then R_UPDATES updates; the op clock counts lanes, and a call
+# sees it after its own.  MN 0 goes down on round 2's Get (its one backoff
+# and retry add R_GETS lanes) and restarts on round 5's Get.
+R_GETS, R_UPDATES = 256, 128
+R_ROUND = R_GETS + R_UPDATES
+CRASH_AT = 2 * R_ROUND + 1
+RESTART_AT = 5 * R_ROUND + R_GETS + 1  # round 4's updates end below it
+REPLICA_SPANS = (wall.REPLICA_FAILOVER, wall.RETRY_BACKOFF,
+                 wall.REPLICA_RESYNC, wall.REPLICA_FANOUT)
+REPLICA_COUNTERS = (wall.REPLICA_FAILOVERS, wall.REPLICA_RESYNCS,
+                    wall.REPLICA_RESYNC_BYTES, wall.RETRY_BACKOFF_LANES)
+
+
+def _replicated():
+    from repro.net import FaultEvent, FaultSchedule
+    crash = FaultEvent("mn_crash", CRASH_AT, RESTART_AT - CRASH_AT, mn=0,
+                       down_s=1.0)
+    return open_store(StoreSpec("outback", load_factor=0.85, replicas=2,
+                                faults=FaultSchedule(events=(crash,))),
+                      KEYS, splitmix64(KEYS))
+
+
+def _replica_session(st, annotate=False):
+    """Seven rounds; each call in a ``client.<op>`` span when annotating."""
+    got = []
+    for r in range(7):
+        gets = KEYS[r * R_GETS:(r + 1) * R_GETS]
+        upd = KEYS[-(r + 1) * R_UPDATES:][:R_UPDATES]
+        for op, call in (("get", lambda: st.get_batch(gets, xp=jnp)),
+                         ("update", lambda: st.update_batch(
+                             upd, splitmix64(upd + np.uint64(r + 1))))):
+            if annotate:
+                with TraceAnnotation(f"client.{op}"):
+                    got.append(call())
+            else:
+                got.append(call())
+    return got
+
+
+def test_replica_counters_of_one_scripted_crash():
+    st = _replicated()
+    before = {n: wall.totals().get(n, 0) for n in REPLICA_COUNTERS}
+    got = _replica_session(st)
+    grown = {n: wall.totals().get(n, 0) - before[n] for n in REPLICA_COUNTERS}
+    rs = st
+    while not hasattr(rs, "replicas"):
+        rs = rs.inner
+    assert grown == {wall.REPLICA_FAILOVERS: 1, wall.REPLICA_RESYNCS: 1,
+                     wall.REPLICA_RESYNC_BYTES:
+                         rs.replicas[1].engine.mn_state_bytes(),
+                     wall.RETRY_BACKOFF_LANES: R_GETS}
+    assert [bool(r.failovers) for r in got[::2]] == \
+        [False, False, True, False, False, False, False]
+
+
+def test_replica_spans_nest_in_their_calls(tmp_path):
+    """Failover and backoff lie in round 2's Get call, side by side; the
+    retried Get's own spans lie in the backoff span; the resync and the
+    fail-back to MN 0 lie in round 5's Get; a fan-out lies in each update
+    call made while both replicas are up.  Answers and meters match an
+    untraced twin run."""
+    plain, traced = _replicated(), _replicated()
+    want = _replica_session(plain)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        got = _replica_session(traced, annotate=True)
+    finally:
+        jax.profiler.stop_trace()
+    xplane = sorted(pathlib.Path(tmp_path).rglob("*.xplane.pb"))[-1]
+    events = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                     for plane in ProfileData.from_file(str(xplane)).planes
+                     for line in plane.lines for e in line.events),
+                    key=lambda ev: ev[1])
+    calls = sorted((s, e) for n, s, e in events if n.startswith("client."))
+    assert len(calls) == 14
+
+    def call_of(s, e):
+        return [i for i, (a, b) in enumerate(calls) if a <= s and e <= b]
+
+    placed = {n: [] for n in REPLICA_SPANS}
+    for n, s, e in events:
+        if n in placed:
+            placed[n] += call_of(s, e)
+    assert placed == {wall.REPLICA_FAILOVER: [4, 10],
+                      wall.RETRY_BACKOFF: [4], wall.REPLICA_RESYNC: [10],
+                      wall.REPLICA_FANOUT: [1, 3, 11, 13]}
+    (f0, f1), (r0, r1) = [(s, e) for n, s, e in events
+                          if n == wall.REPLICA_FAILOVER]
+    (s0, s1), = [(s, e) for n, s, e in events if n == wall.REPLICA_RESYNC]
+    assert s1 <= r0
+    (b0, b1), = [(s, e) for n, s, e in events if n == wall.RETRY_BACKOFF]
+    assert f1 <= b0
+    assert [n for n, s, e in events if b0 <= s and e <= b1
+            and n.startswith("repro.get.")] == GET_SPANS
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_array_equal(g.values, w.values)
+        np.testing.assert_array_equal(g.found, w.found)
+    assert traced.meter_totals().snapshot() == plain.meter_totals().snapshot()
